@@ -44,8 +44,8 @@ def _paged_case(n_pages, bs, B=4, nkv=2, hd=64, seed=7):
     dense gather: the whole table materializes)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     P = B * n_pages + 1
-    kpool = jax.random.normal(ks[0], (P, bs, nkv, hd))
-    vpool = jax.random.normal(ks[1], (P, bs, nkv, hd))
+    kpool = jax.random.normal(ks[0], (P, nkv, bs, hd))
+    vpool = jax.random.normal(ks[1], (P, nkv, bs, hd))
     q = jax.random.normal(ks[2], (B, 2 * nkv, hd))
     table = (jnp.arange(B * n_pages, dtype=jnp.int32) + 1).reshape(B, n_pages)
     pos = jnp.full((B,), n_pages * bs - 1, jnp.int32)

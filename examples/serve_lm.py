@@ -42,7 +42,7 @@ def main():
     ap.add_argument("--no-dashboard", dest="dashboard",
                     action="store_false")
     args = ap.parse_args()
-    argv = [sys.argv[0], "--arch", args.arch,
+    argv = [sys.argv[0], "--arch", args.arch, "--reduced",
             "--requests", str(args.requests),
             "--replicas", str(args.replicas),
             "--policy", args.policy,

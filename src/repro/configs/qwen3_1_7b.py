@@ -1,5 +1,6 @@
 """qwen3-1.7b [dense] — 28L d_model=2048 16H (GQA kv=8, head_dim=128)
-d_ff=6144 vocab=151936, qk_norm. [hf:Qwen/Qwen3-8B]"""
+d_ff=6144 vocab=151936, qk_norm, bf16 like the published checkpoint.
+[hf:Qwen/Qwen3-1.7B]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -9,5 +10,6 @@ CONFIG = ModelConfig(
     qk_norm=True,
     tie_embeddings=True, act="silu", rope_theta=1_000_000.0,
     long_context_window=4096,
-    source="[hf:Qwen/Qwen3-8B]",
+    dtype="bfloat16", param_dtype="bfloat16",
+    source="[hf:Qwen/Qwen3-1.7B]",
 )

@@ -1,3 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the model's hot spots. Each has kernel.py (the
+Pallas body), ops.py (the jitted public wrapper) and ref.py (the pure-jnp
+oracle)."""
+import jax
+
+
+def default_interpret() -> bool:
+    """The one platform rule for every kernel wrapper: compile on a TPU,
+    run the kernel body in the Pallas interpreter anywhere else."""
+    return jax.default_backend() != "tpu"

@@ -1,6 +1,6 @@
 """Jitted public wrapper for the flash-attention kernel: pads ragged
 sequence lengths up to block multiples, dispatches to the Pallas kernel
-(interpret=True executes the kernel body in Python on CPU), and slices the
+(compiled on a TPU, run in the Pallas interpreter elsewhere), and slices the
 padding back off."""
 from __future__ import annotations
 
@@ -9,6 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
@@ -26,8 +27,10 @@ def _pad_to(x, mult, axis):
                                              "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
-                    block_q=128, block_k=128, interpret=False):
+                    block_q=128, block_k=128, interpret=None):
     """Public entry. q: (B, Sq, nh, hd); k, v: (B, Sk, nkv, hd)."""
+    if interpret is None:
+        interpret = default_interpret()
     Sq, Sk = q.shape[1], k.shape[1]
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)

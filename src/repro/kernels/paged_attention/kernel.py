@@ -62,8 +62,8 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         rep = q_ref.shape[2]
         q = q_ref[0, 0].astype(jnp.float32)                  # (rep, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         # a page's gather index IS its absolute position: token o of page j
@@ -72,12 +72,12 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, (rep, block_size), 1)
         mask = kv_pos <= pos
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]                                  # (rep,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                                  # (rep, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + \
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + \
             jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
@@ -86,17 +86,17 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     def _finalize():
         l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, kpool, vpool, table, pos, *, scale=None,
                            interpret=False):
-    """q: (B, nh, hd) one query token per slot; kpool/vpool: (P, bs, nkv,
+    """q: (B, nh, hd) one query token per slot; kpool/vpool: (P, nkv, bs,
     hd) block-pool pages; table: (B, nb) int32 block ids per slot; pos:
     (B,) int32 absolute position of the query token. Returns (B, nh, hd).
     """
     B, nh, hd = q.shape
-    _, bs, nkv, _ = kpool.shape
+    _, nkv, bs, _ = kpool.shape
     nb = table.shape[1]
     rep = nh // nkv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
@@ -113,18 +113,19 @@ def paged_attention_kernel(q, kpool, vpool, table, pos, *, scale=None,
         in_specs=[
             pl.BlockSpec((1, 1, rep, hd),
                          lambda b, h, j, tbl, pos: (b, h, 0, 0)),
-            # the table walk: page j of slot b lives at pool row tbl[b, j]
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
+            # the table walk: page j of slot b lives at pool row tbl[b, j];
+            # the block's last two dims are one head's whole (bs, hd) page
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda b, h, j, tbl, pos: (tbl[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda b, h, j, tbl, pos: (tbl[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd),
                                lambda b, h, j, tbl, pos: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -1,8 +1,8 @@
 """Jitted public wrapper for paged-attention decode.
 
-``kernel="pallas"`` dispatches to the Pallas kernel (interpret=True
-executes the kernel body in Python on CPU — the default off-TPU, so the
-same BlockSpecs/grid the TPU lowering uses are exercised everywhere);
+``kernel="pallas"`` dispatches to the Pallas kernel (compiled on a TPU;
+elsewhere it runs in the Pallas interpreter, ``repro.kernels.
+default_interpret``, so the same BlockSpecs/grid are exercised everywhere);
 ``kernel="reference"`` runs the dense-gather oracle (ref.py), which is the
 pre-kernel production path and the CPU fallback of record.
 
@@ -17,14 +17,11 @@ import functools
 
 import jax
 
+from repro.kernels import default_interpret
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
 KERNELS = ("pallas", "reference")
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "kernel",
@@ -41,7 +38,7 @@ def _dispatch(q, kpool, vpool, table, pos, *, scale, window, kernel,
 def paged_attention(q, kpool, vpool, table, pos, *, scale=None, window=None,
                     kernel="reference", interpret=None):
     """Public entry. q: (B, nh, hd) single query token per slot;
-    kpool/vpool: (P, bs, nkv, hd); table: (B, nb); pos: (B,).
+    kpool/vpool: (P, nkv, bs, hd); table: (B, nb); pos: (B,).
     Returns (B, nh, hd). The default matches the stack above it
     (engine/gateway/launcher): "reference" everywhere until a TPU is the
     target — interpret-mode pallas is for oracle tests, not speed."""
@@ -53,6 +50,6 @@ def paged_attention(q, kpool, vpool, table, pos, *, scale=None, window=None,
                          "not a ring); use kernel='reference' or the dense "
                          "layout for sliding-window decode")
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _dispatch(q, kpool, vpool, table, pos, scale=scale,
                      window=window, kernel=kernel, interpret=interpret)

@@ -29,17 +29,16 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, block_s: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)         # (block_s, block_c)
-    b = b_ref[0].astype(jnp.float32)
-
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        y_ref[0, t, :] = h.astype(y_ref.dtype)
+    def step(t, h):
+        # one (1, block_c) row of the tile per time step, read and written
+        # through the refs at a dynamic sublane offset
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :].astype(jnp.float32) * h + \
+            b_ref[0, row, :].astype(jnp.float32)
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, h_ref[...])
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, block_s, step, h_ref[...])
 
 
 def rglru_scan_kernel(a, b, *, block_s=128, block_c=128, interpret=False):
@@ -61,6 +60,6 @@ def rglru_scan_kernel(a, b, *, block_s=128, block_c=128, interpret=False):
         out_specs=pl.BlockSpec((1, block_s, block_c),
                                lambda ib, ic, isb: (ib, isb, ic)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_c,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
     )(a, b)

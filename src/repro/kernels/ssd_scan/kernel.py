@@ -8,7 +8,9 @@ intra-chunk quadratic term and the state update as (chunk x N) @ (N x P)
 matmuls for the MXU. Grid = (batch*heads, n_chunks).
 
 Inputs are pre-arranged head-major: xdt (BH, S, P) [x already scaled by
-dt], a (BH, S) [log decay dt*A], B, C (BH, S, N) [group-broadcast].
+dt], a (BH, S) [log decay dt*A], B, C (BH, S, N) [group-broadcast]. The
+wrapper turns ``a`` into its within-chunk cumulative sum, blocked as
+(chunk, 1) and (1, chunk) tiles.
 Outputs: y (BH, S, P) and the final state (BH, P, N).
 """
 from __future__ import annotations
@@ -21,8 +23,8 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 
-def _ssd_kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
-                state_ref, *, chunk: int, n_chunks: int):
+def _ssd_kernel(xdt_ref, acs_col_ref, acs_row_ref, b_ref, c_ref, y_ref,
+                state_out_ref, state_ref, *, chunk: int, n_chunks: int):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
@@ -30,16 +32,15 @@ def _ssd_kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     xdt = xdt_ref[0].astype(jnp.float32)            # (q, P)
-    a = a_ref[0].astype(jnp.float32)                # (q,)
+    acs = acs_col_ref[0]                            # (q, 1) inclusive
+    acs_row = acs_row_ref[0]                        # (1, q) same values
     B = b_ref[0].astype(jnp.float32)                # (q, N)
     C = c_ref[0].astype(jnp.float32)                # (q, N)
 
-    acs = jnp.cumsum(a)                             # inclusive (q,)
     # intra-chunk: scores[i,j] = C_i.B_j * exp(acs_i - acs_j), i >= j
-    seg = acs[:, None] - acs[None, :]
     tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
+    L = jnp.where(tri, jnp.exp(acs - acs_row), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * L
     y = jax.lax.dot_general(scores, xdt, (((1,), (0,)), ((), ())),
@@ -47,17 +48,21 @@ def _ssd_kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
 
     # carried-state contribution: (C * exp(acs)) @ state^T : (q,N)@(N,P)
     state = state_ref[...]                          # (P, N)
-    y += jax.lax.dot_general(C * jnp.exp(acs)[:, None], state,
+    y += jax.lax.dot_general(C * jnp.exp(acs), state,
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
     # state update: S' = exp(acs_last)*S + sum_j exp(acs_last-acs_j) xdt_j B_j^T
-    decay_j = jnp.exp(acs[-1] - acs)                # (q,)
-    upd = jax.lax.dot_general(xdt * decay_j[:, None], B,
+    # acs[-1] as a masked lane sum: the TPU compiler refuses to broadcast
+    # a (1, 1) slice taken at lane offset chunk - 1
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    acs_last = jnp.sum(jnp.where(last, acs_row, 0.0), axis=1,
+                       keepdims=True)               # (1, 1)
+    upd = jax.lax.dot_general(xdt * jnp.exp(acs_last - acs), B,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (P, N)
-    state_ref[...] = jnp.exp(acs[-1]) * state + upd
+    state_ref[...] = jnp.exp(acs_last) * state + upd
 
     @pl.when(ic == n_chunks - 1)
     def _emit_state():
@@ -71,13 +76,18 @@ def ssd_scan_kernel(xdt, a, B, C, *, chunk: int, interpret=False):
     N = B.shape[-1]
     assert S % chunk == 0, (S, chunk)
     n_chunks = S // chunk
+    # the within-chunk cumulative log decay, once as a column and once as a
+    # row, so the kernel forms exp(acs_i - acs_j) by broadcasting alone
+    acs = jnp.cumsum(a.astype(jnp.float32).reshape(BH, n_chunks, chunk),
+                     axis=-1).reshape(BH, S)
     kern = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=n_chunks)
     y, state = pl.pallas_call(
         kern,
         grid=(BH, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, ic: (bh, ic)),
+            pl.BlockSpec((1, chunk, 1), lambda bh, ic: (bh, ic, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, ic: (bh, 0, ic)),
             pl.BlockSpec((1, chunk, N), lambda bh, ic: (bh, ic, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ic: (bh, ic, 0)),
         ],
@@ -91,5 +101,5 @@ def ssd_scan_kernel(xdt, a, B, C, *, chunk: int, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xdt, a, B, C)
+    )(xdt, acs[:, :, None], acs[:, None, :], B, C)
     return y, state

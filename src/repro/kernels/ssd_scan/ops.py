@@ -8,13 +8,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_size", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk_size=128, interpret=False):
+def ssd_scan(x, dt, A, B, C, *, chunk_size=128, interpret=None):
     """Same contract as models.mamba2.ssd_chunked: x (b,s,h,p), dt (b,s,h),
     A (h,), B/C (b,s,g,n) -> (y (b,s,h,p) x.dtype, state (b,h,p,n) f32)."""
+    if interpret is None:
+        interpret = default_interpret()
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     rep = h // g

@@ -52,9 +52,9 @@ def make_sharded_step(cfg, mesh, opt_update, p_specs, o_specs, batch_example,
     step = build_lm_train_step(cfg, opt_update, microbatches=microbatches)
     metric_specs = None    # let XLA replicate scalars
     jitted = jax.jit(step,
-                     in_shardings=R.as_shardings(
+                     in_shardings=R.to_shardings(
                          mesh, (p_specs, o_specs, b_specs)),
-                     out_shardings=R.as_shardings(
+                     out_shardings=R.to_shardings(
                          mesh, (p_specs, o_specs, metric_specs)),
                      donate_argnums=(0, 1))
     return jitted, b_specs
@@ -70,7 +70,7 @@ def train_sharded(cfg, mesh, data: Iterable, *, num_steps: int, lr=3e-4,
                   microbatches: int = 1, seed: int = 0, log_every: int = 10,
                   verbose: bool = True):
     """End-to-end sharded training loop. Returns (params, opt_state, losses)."""
-    with R.mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = T.init_lm(jax.random.PRNGKey(seed), cfg)
         opt_init, opt_update = adamw(
             linear_warmup_cosine(lr, max(num_steps // 10, 1), num_steps))

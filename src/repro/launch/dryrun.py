@@ -63,12 +63,12 @@ def parse_collectives(hlo_text: str) -> dict:
 
 def _compile_case(cfg, shape_name, mesh, *, microbatches=None, remat=None):
     t0 = time.perf_counter()
-    with R.mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         case = make_case(cfg, shape_name, mesh, microbatches=microbatches,
                          remat=remat)
         jitted = jax.jit(case["fn"],
-                         in_shardings=R.as_shardings(mesh, case["in_specs"]),
-                         out_shardings=R.as_shardings(mesh,
+                         in_shardings=R.to_shardings(mesh, case["in_specs"]),
+                         out_shardings=R.to_shardings(mesh,
                                                       case["out_specs"]),
                          donate_argnums=case["donate"])
         lowered = jitted.lower(*case["args"])

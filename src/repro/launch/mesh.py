@@ -7,26 +7,29 @@ Functions, not module constants — importing this module never touches jax
 device state (smoke tests must keep seeing 1 CPU device). When more devices
 exist than a mesh needs (e.g. the 512-device dry-run process building the
 single-pod 256 mesh), the first prod(shape) devices are used.
+
+Every axis is ``Auto``: the model code places tensors with sharding
+constraints and leaves the rest to the partitioner, so no operation needs
+an explicit output sharding.
 """
 from __future__ import annotations
 
 import math
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
     n = math.prod(shape)
     devs = jax.devices()
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
     if len(devs) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             f"(launch/dryrun.py does this)")
-    return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False):

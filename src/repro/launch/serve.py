@@ -1,5 +1,6 @@
-"""Serving launcher: batched requests against a (reduced) model through the
-queue-backed gateway — replica dispatch policies, per-request sampling,
+"""Serving launcher: batched requests against a model (its published
+widths, or --reduced for a CPU-sized variant) through the queue-backed
+gateway — replica dispatch policies, per-request sampling,
 optional token streaming, multi-tenant workload replay with per-tier SLO
 judgment, an armable anomaly flight recorder, and a Fig 6/7-shaped
 telemetry dashboard."""
@@ -14,6 +15,7 @@ from repro.configs import registry
 from repro.core import reporting
 from repro.gateway.gateway import POLICIES, BrownoutConfig, Gateway
 from repro.gateway.sampler import SamplingParams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import trace as otrace
 from repro.obs import slo as oslo
@@ -74,6 +76,10 @@ def _drive(gw: Gateway, cfg, args) -> tuple:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.ARCH_IDS)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the CPU-sized variant of the arch "
+                    "(configs/registry.reduce_config) instead of its "
+                    "published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--replicas", type=int, default=1)
@@ -219,7 +225,8 @@ def main():
     if args.trace:
         otrace.enable()
 
-    cfg = registry.get(args.arch, reduced=True)
+    enable_compile_cache()
+    cfg = registry.get(args.arch, reduced=args.reduced)
     if cfg.is_encdec:
         raise SystemExit("serve launcher drives decoder-only archs; "
                          "enc-dec serving goes through serve/step.py")
@@ -315,6 +322,12 @@ def main():
                 print(f"[serve] flight recorder: {fl['dumps']} dump(s), "
                       f"last -> {fl['last_dump']}")
             gw.flight.disarm()
+    if not done:
+        # a replica that raised was failed forward by the gateway; with no
+        # request served at all, the run did not work
+        errors = [r.last_error for r in gw.replicas if r.last_error]
+        raise SystemExit(f"[serve] no request finished; replica errors: "
+                         f"{errors or 'none recorded'}")
     toks = sum(len(r.output) for r in done)
     print(f"[serve] {len(done)} requests, {toks} tokens in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s, {args.replicas}x{args.slots} slots, "

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs import registry
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw
 from repro.optim.schedules import linear_warmup_cosine
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = registry.get(args.arch, reduced=args.reduced)
     print(f"[train] {cfg.arch_id} reduced={args.reduced} "
           f"params~{cfg.param_count()/1e6:.1f}M backend={jax.default_backend()}")

@@ -8,7 +8,7 @@ Attention supports:
   * causal, sliding-window and cross (non-causal) masking
   * decode against a (possibly ring-buffered) KV cache
   * impl = "xla" (einsum; what the dry-run lowers) or "pallas"
-    (kernels/flash_attention; interpret-mode on CPU)
+    (kernels/flash_attention; interpret mode off the TPU)
 """
 from __future__ import annotations
 
@@ -161,8 +161,7 @@ def attention(params, cfg, x, positions, *, kv=None, kv_positions=None,
     scale = 1.0 / math.sqrt(hd)
     if cfg.attention_impl == "pallas" and kv is None and causal:
         from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=True, window=window,
-                                     interpret=True)
+        out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
         mask = _attn_mask(jnp.broadcast_to(positions, (B, S)),
                           jnp.broadcast_to(kv_positions, (B, src.shape[1])),
@@ -218,12 +217,26 @@ def attention_decode(params, cfg, x, pos, cache_k, cache_v, cache_pos, *,
     return out, cache_k, cache_v, cache_pos
 
 
+def _pool_write(pool, blk, off, kv):
+    """Scatter K or V rows kv (..., nkv, hd) into a (P, nkv, bs, hd) pool:
+    row i lands in page blk[i] at offset off[i], every head at once."""
+    return pool.at[blk, :, off].set(kv)
+
+
+def _pool_chain(pool, table):
+    """Dense view of the page chains named by table (B, nb): returns
+    (B, nb*bs, nkv, hd), whose index along axis 1 IS the absolute
+    position."""
+    g = jnp.swapaxes(jnp.take(pool, table, axis=0), -3, -2)
+    return g.reshape(g.shape[:-4] + (-1,) + g.shape[-2:])
+
+
 def attention_decode_paged(params, cfg, x, pos, kpool, vpool, table, *,
                            window=None, rope=True, kernel="reference"):
     """Single-token decode over a *paged* KV cache (block tables).
 
     x: (B, 1, d); pos: (B,) absolute position of the new token.
-    kpool/vpool: (P, bs, nkv, hd) — pool row b holds the bs-token KV page of
+    kpool/vpool: (P, nkv, bs, hd) — pool row b holds the bs-token KV page of
     block id b for this layer. table: (B, nb) int32 block ids per slot; page
     j of slot s holds positions [j*bs, (j+1)*bs). Returns
     (out, new_kpool, new_vpool).
@@ -242,7 +255,7 @@ def attention_decode_paged(params, cfg, x, pos, kpool, vpool, table, *,
     """
     B, _, d = x.shape
     hd, nh, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     q = (x @ params["wq"]).reshape(B, 1, nh, hd)
     k_new = (x @ params["wk"]).reshape(B, 1, nkv, hd)
     v_new = (x @ params["wv"]).reshape(B, 1, nkv, hd)
@@ -254,8 +267,8 @@ def attention_decode_paged(params, cfg, x, pos, kpool, vpool, table, *,
         k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta)
     blk = jnp.take_along_axis(table, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
-    kpool = kpool.at[blk, off].set(k_new[:, 0])
-    vpool = vpool.at[blk, off].set(v_new[:, 0])
+    kpool = _pool_write(kpool, blk, off, k_new[:, 0])
+    vpool = _pool_write(vpool, blk, off, v_new[:, 0])
     from repro.kernels.paged_attention import ops as pa_ops
     out = pa_ops.paged_attention(q[:, 0], kpool, vpool, table, pos,
                                  window=window, kernel=kernel)
@@ -274,7 +287,7 @@ def attention_verify_paged(params, cfg, x, pos, kpool, vpool, table, *,
     decode steps).
 
     x: (B, T, d); pos: (B,) absolute position of each slot's first token.
-    kpool/vpool: (P, bs, nkv, hd); table: (B, nb). Returns
+    kpool/vpool: (P, nkv, bs, hd); table: (B, nb). Returns
     (out (B, T, d), new_kpool, new_vpool).
 
     Positions that overflow the slot's table span (a draft burst near the
@@ -285,7 +298,7 @@ def attention_verify_paged(params, cfg, x, pos, kpool, vpool, table, *,
     """
     B, T, d = x.shape
     hd, nh, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     nb = table.shape[1]
     q = (x @ params["wq"]).reshape(B, T, nh, hd)
     k = (x @ params["wk"]).reshape(B, T, nkv, hd)
@@ -301,10 +314,10 @@ def attention_verify_paged(params, cfg, x, pos, kpool, vpool, table, *,
     page = jnp.clip(q_pos // bs, 0, nb - 1)
     blk = jnp.where(in_span, jnp.take_along_axis(table, page, axis=1), 0)
     off = jnp.where(in_span, q_pos % bs, 0)
-    kpool = kpool.at[blk, off].set(k)
-    vpool = vpool.at[blk, off].set(v)
-    kall = jnp.take(kpool, table, axis=0).reshape(B, nb * bs, nkv, hd)
-    vall = jnp.take(vpool, table, axis=0).reshape(B, nb * bs, nkv, hd)
+    kpool = _pool_write(kpool, blk, off, k)
+    vpool = _pool_write(vpool, blk, off, v)
+    kall = _pool_chain(kpool, table)
+    vall = _pool_chain(vpool, table)
     kv_pos = jnp.arange(nb * bs)
     mask = kv_pos[None, None, :] <= q_pos[:, :, None]            # (B, T, Sk)
     if window is not None:
@@ -330,7 +343,7 @@ def attention_prefill_paged(params, cfg, x, q_pos, n_tok, kpool, vpool,
     """
     B, S, d = x.shape
     hd, nh, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     nb = table.shape[0]
     q = (x @ params["wq"]).reshape(B, S, nh, hd)
     k = (x @ params["wk"]).reshape(B, S, nkv, hd)
@@ -344,10 +357,10 @@ def attention_prefill_paged(params, cfg, x, q_pos, n_tok, kpool, vpool,
     real = jnp.arange(S) < n_tok
     blk = jnp.where(real, jnp.take(table, q_pos // bs, axis=0), 0)
     off = jnp.where(real, q_pos % bs, 0)
-    kpool = kpool.at[blk, off].set(k[0])
-    vpool = vpool.at[blk, off].set(v[0])
-    kall = jnp.take(kpool, table, axis=0).reshape(1, nb * bs, nkv, hd)
-    vall = jnp.take(vpool, table, axis=0).reshape(1, nb * bs, nkv, hd)
+    kpool = _pool_write(kpool, blk, off, k[0])
+    vpool = _pool_write(vpool, blk, off, v[0])
+    kall = _pool_chain(kpool, table[None])
+    vall = _pool_chain(vpool, table[None])
     kv_pos = jnp.arange(nb * bs)
     mask = kv_pos[None, :] <= q_pos[:, None]             # causal, absolute
     if window is not None:
@@ -388,7 +401,7 @@ def attention_mixed_paged(params, cfg, x, pos, n_chunk, kpool, vpool, table,
     """
     R = x.shape[1]
     hd, nh, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     B = table.shape[0]
     C = R - B
     nbc = ctable.shape[0]
@@ -412,15 +425,15 @@ def attention_mixed_paged(params, cfg, x, pos, n_chunk, kpool, vpool, table,
                         0)
     blk = jnp.concatenate([dec_blk, chk_blk])
     off = jnp.concatenate([pos[:B] % bs, jnp.where(real, cpos % bs, 0)])
-    kpool = kpool.at[blk, off].set(k)
-    vpool = vpool.at[blk, off].set(v)
+    kpool = _pool_write(kpool, blk, off, k)
+    vpool = _pool_write(vpool, blk, off, v)
     # read 1: per-slot decode attention (kernel-switched, as decode_paged)
     from repro.kernels.paged_attention import ops as pa_ops
     out_dec = pa_ops.paged_attention(q[:B], kpool, vpool, table, pos[:B],
                                      window=window, kernel=kernel)
     # read 2: the chunk attends its truncated chain, causal by position
-    kall = jnp.take(kpool, ctable, axis=0).reshape(1, nbc * bs, nkv, hd)
-    vall = jnp.take(vpool, ctable, axis=0).reshape(1, nbc * bs, nkv, hd)
+    kall = _pool_chain(kpool, ctable[None])
+    vall = _pool_chain(vpool, ctable[None])
     kv_pos = jnp.arange(nbc * bs)
     mask = kv_pos[None, :] <= cpos[:, None]
     if window is not None:
@@ -458,7 +471,7 @@ def attention_prefill_chunk_paged(params, cfg, x, start, n_tok, kpool, vpool,
     """
     B, S, d = x.shape
     hd, nh, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     nb = table.shape[0]
     q = (x @ params["wq"]).reshape(B, S, nh, hd)
     k = (x @ params["wk"]).reshape(B, S, nkv, hd)
@@ -474,10 +487,10 @@ def attention_prefill_chunk_paged(params, cfg, x, start, n_tok, kpool, vpool,
     page = jnp.clip(q_pos // bs, 0, nb - 1)
     blk = jnp.where(real, jnp.take(table, page, axis=0), 0)
     off = jnp.where(real, q_pos % bs, 0)
-    kpool = kpool.at[blk, off].set(k[0])
-    vpool = vpool.at[blk, off].set(v[0])
-    kall = jnp.take(kpool, table, axis=0).reshape(1, nb * bs, nkv, hd)
-    vall = jnp.take(vpool, table, axis=0).reshape(1, nb * bs, nkv, hd)
+    kpool = _pool_write(kpool, blk, off, k[0])
+    vpool = _pool_write(vpool, blk, off, v[0])
+    kall = _pool_chain(kpool, table[None])
+    vall = _pool_chain(vpool, table[None])
     kv_pos = jnp.arange(nb * bs)
     mask = kv_pos[None, :] <= q_pos[:, None]             # causal, absolute
     if window is not None:

@@ -163,8 +163,7 @@ def mamba2_forward(params, cfg, u):
     A = -jnp.exp(params["A_log"])
     if cfg.attention_impl == "pallas":
         from repro.kernels.ssd_scan import ops as ssd_ops
-        y, state = ssd_ops.ssd_scan(x, dt, A, Bm, Cm, chunk_size=s.chunk_size,
-                                    interpret=True)
+        y, state = ssd_ops.ssd_scan(x, dt, A, Bm, Cm, chunk_size=s.chunk_size)
     else:
         y, state = ssd_chunked(x, dt, A, Bm, Cm, s.chunk_size)
     y = y + x * params["D"][None, None, :, None]

@@ -57,7 +57,7 @@ def rglru_scan(params, cfg, x, h0=None):
     a, gx = _gates(params, cfg, x)                                    # (B,S,w) f32
     if cfg.attention_impl == "pallas" and h0 is None:
         from repro.kernels.rglru_scan import ops as rg_ops
-        y, h_fin = rg_ops.rglru_scan(a, gx, interpret=True)
+        y, h_fin = rg_ops.rglru_scan(a, gx)
         return y.astype(x.dtype), h_fin
     if h0 is not None:
         # fold initial state in as a virtual step 0 with a=1 (identity decay)

@@ -391,8 +391,9 @@ def paged_supported(cfg) -> bool:
 
 
 def init_paged_cache(cfg, n_pool_blocks: int, block_size: int):
-    """Pool-shaped KV cache: per attention layer, row b of the (P, bs, nkv,
-    hd) pool arrays is the bs-token page named by block id b. The same
+    """Pool-shaped KV cache: per attention layer, row b of the (P, nkv, bs,
+    hd) pool arrays is the bs-token page named by block id b (heads outside
+    tokens, so one head's page is a (bs, hd) tile for the decode kernel). The same
     block id indexes every layer, so one host-side block table describes a
     sequence across the whole stack. Structure mirrors `init_cache`
     ("blocks" stacked on a leading n_blocks axis, "tail" unrolled) so the
@@ -405,8 +406,8 @@ def init_paged_cache(cfg, n_pool_blocks: int, block_size: int):
     adt = cfg.activation_dtype
 
     def one():
-        return {"k": jnp.zeros((n_pool_blocks, block_size, nkv, hd), adt),
-                "v": jnp.zeros((n_pool_blocks, block_size, nkv, hd), adt)}
+        return {"k": jnp.zeros((n_pool_blocks, nkv, block_size, hd), adt),
+                "v": jnp.zeros((n_pool_blocks, nkv, block_size, hd), adt)}
 
     def stack(tree, n):
         return jax.tree.map(

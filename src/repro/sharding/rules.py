@@ -21,24 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # -------------------------------------------------------------- mesh helpers
 
-def mesh_context(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions:
-    `jax.sharding.set_mesh` where it exists (newer jax), else the legacy
-    `with mesh:` — both make `mesh` ambient for the enclosed computation."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
-def as_shardings(mesh: Mesh, tree):
-    """PartitionSpec tree -> NamedSharding tree for jit in/out_shardings.
-    Newer jax resolves bare PartitionSpecs against the ambient mesh; older
-    jax requires concrete Shardings — explicit conversion works on both.
-    None leaves (unspecified/auto) pass through."""
-    return jax.tree.map(
-        lambda s: None if s is None else NamedSharding(mesh, s),
-        tree, is_leaf=lambda x: x is None or isinstance(x, P))
-
-
 def mesh_axis_size(mesh: Mesh, name: str) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(name, 1)
 
@@ -60,27 +42,24 @@ def constrain(x, dims):
     """Soft sharding constraint usable inside mesh-agnostic model code.
 
     dims: per-dimension tag — "batch" | "model" | None. Resolved against the
-    ambient mesh (set by `jax.sharding.use_mesh` / `with mesh:` in the
-    launcher); a no-op when there is no mesh (CPU unit tests).
+    ambient mesh (set by `jax.sharding.set_mesh` in the launcher); a no-op
+    when there is no mesh (CPU unit tests).
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        spec = []
-        for i, d in enumerate(dims):
-            if d == "batch":
-                axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-                ok = axes and x.shape[i] % _abstract_size(mesh, axes) == 0
-                spec.append(axes if ok else None)
-            elif d == "model" and "model" in mesh.axis_names:
-                ok = x.shape[i] % _abstract_size(mesh, ("model",)) == 0
-                spec.append("model" if ok else None)
-            else:
-                spec.append(None)
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    spec = []
+    for i, d in enumerate(dims):
+        if d == "batch":
+            axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+            ok = axes and x.shape[i] % _abstract_size(mesh, axes) == 0
+            spec.append(axes if ok else None)
+        elif d == "model" and "model" in mesh.axis_names:
+            ok = x.shape[i] % _abstract_size(mesh, ("model",)) == 0
+            spec.append("model" if ok else None)
+        else:
+            spec.append(None)
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def _abstract_size(mesh, axes) -> int:

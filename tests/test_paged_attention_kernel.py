@@ -25,8 +25,8 @@ def _case(B, nb, bs, nkv, rep, hd, fills, seed=0):
     table); pos[b] = fills[b] - 1, the newest token's position."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     P = B * nb + 1
-    kpool = jax.random.normal(ks[0], (P, bs, nkv, hd))
-    vpool = jax.random.normal(ks[1], (P, bs, nkv, hd))
+    kpool = jax.random.normal(ks[0], (P, nkv, bs, hd))
+    vpool = jax.random.normal(ks[1], (P, nkv, bs, hd))
     q = jax.random.normal(ks[2], (B, nkv * rep, hd))
     rows = list(range(1, P))
     table = np.zeros((B, nb), np.int32)

@@ -237,7 +237,7 @@ def test_mixed_step_matches_chunk_prefill_oracle(model):
     assert outs_f == outs_o
     for lf, lo in zip(jax.tree.leaves(cache_f), jax.tree.leaves(cache_o)):
         # exclude pool row 0 (the reserved null page, axis -4 of every
-        # (..., P, bs, nkv, hd) leaf): the fused step's masked decode rows
+        # (..., P, nkv, bs, hd) leaf): the fused step's masked decode rows
         # and the oracle's pad rows both dump different junk there; every
         # real page must match the oracle exactly
         lf = np.asarray(lf)[..., 1:, :, :, :]

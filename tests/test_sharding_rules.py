@@ -74,13 +74,13 @@ def test_reduced_case_lowers_on_debug_mesh(arch, shape):
     small = S.ShapeCase(case_obj.name, case_obj.kind, 64, 8)
     try:
         S.SHAPES[shape] = small
-        with R.mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             case = make_case(cfg, shape, mesh, microbatches=2
                              if case_obj.kind == "train" else None)
             jitted = jax.jit(
                 case["fn"],
-                in_shardings=R.as_shardings(mesh, case["in_specs"]),
-                out_shardings=R.as_shardings(mesh, case["out_specs"]),
+                in_shardings=R.to_shardings(mesh, case["in_specs"]),
+                out_shardings=R.to_shardings(mesh, case["out_specs"]),
                 donate_argnums=case["donate"])
             compiled = jitted.lower(*case["args"]).compile()
             assert compiled.cost_analysis() is not None
