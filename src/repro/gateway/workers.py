@@ -25,6 +25,16 @@ the whole fleet. With `async_workers=True` each `EngineReplica` gets a
       - heartbeat again, notify consumers, idle-wait when there was
         nothing to do.
 
+Spans (`repro.obs.trace`, on the replica's track): `worker.pump` is one
+`_pump()` call, with arg `stepped` (1 when it stepped the engine);
+`worker.step` is the `engine.step()` inside it; `worker.idle` runs from
+the first pump that found nothing to do to the start of the next pump that
+stepped, covering every empty pump and `_work_ready.wait` between, as one
+span. So `worker.idle` and the stepped `worker.pump`s tile the thread's
+time, and a stepped pump splits into pump overhead (`worker.pump` less
+`worker.step`), engine host work (`worker.step` less its `engine.wait`)
+and the wait on the device (`engine.wait`).
+
 Lease heartbeats (`extend_leases` immediately before and after each
 engine dispatch) are the liveness signal: a worker that stops pumping
 lets its leases lapse and the queue redelivers to surviving replicas.
@@ -94,6 +104,7 @@ class ReplicaWorker(threading.Thread):
         rid = self.replica.replica_id
         otrace.set_track_name(otrace.HOST_PID, rid, f"replica{rid}")
         gw = self.gateway
+        idle_t0 = None          # start of the current run of empty pumps
         while not self._stop_ev.is_set():
             if self.gate is not None:
                 self.gate.checkpoint("pump")
@@ -102,18 +113,31 @@ class ReplicaWorker(threading.Thread):
             if self._die.is_set():
                 return                      # simulated crash: no cleanup
             self.pumps += 1
-            try:
-                progressed = self._pump()
-            except Exception:   # noqa: BLE001 — a pump bug must not
-                # silently kill the thread; log, count, keep serving
-                self.pump_errors += 1
-                logger.exception("replica %d worker pump failed", rid)
-                progressed = False
-            if not progressed and self.gate is None:
+            t = time.perf_counter()
+            with otrace.span("worker.pump", tid=rid) as span:
+                try:
+                    progressed = self._pump()
+                except Exception:   # noqa: BLE001 — a pump bug must not
+                    # silently kill the thread; log, count, keep serving
+                    self.pump_errors += 1
+                    logger.exception("replica %d worker pump failed", rid)
+                    progressed = False
+                span.set(stepped=int(progressed))
+            if progressed:
+                if idle_t0 is not None:
+                    otrace.add_span("worker.idle", idle_t0, t, tid=rid)
+                    idle_t0 = None
+                continue
+            if idle_t0 is None:
+                idle_t0 = t
+            if self.gate is None:
                 # idle: wait for submit()/progress to kick us (timeout so
                 # probation expiry and lease churn are still observed)
                 with gw._work_ready:
                     gw._work_ready.wait(self.idle_wait_s)
+        if idle_t0 is not None:
+            otrace.add_span("worker.idle", idle_t0, time.perf_counter(),
+                            tid=rid)
 
     def _pump(self) -> bool:
         gw, rep = self.gateway, self.replica
@@ -145,7 +169,8 @@ class ReplicaWorker(threading.Thread):
             # the lock is NOT held here: this is the overlap — my device
             # compute runs while peers dispatch/step; on_token/on_finish
             # callbacks take the gateway lock for their brief bookkeeping
-            eng.step()
+            with otrace.span("worker.step", tid=rep.replica_id):
+                eng.step()
         except Exception as err:    # noqa: BLE001 — fail forward
             with gw._lock:
                 gw._fail_replica(rep, err)

@@ -133,5 +133,6 @@ def paged_attention_kernel(q, kpool, vpool, table, pos, *, scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )(table.astype(jnp.int32), pos.astype(jnp.int32), qr, kpool, vpool)
     return out.reshape(B, nh, hd)

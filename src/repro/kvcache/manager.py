@@ -23,6 +23,7 @@ from typing import List, Optional, Tuple
 from repro.kvcache.block_pool import BlockPool, PoolExhausted
 from repro.kvcache.metrics import CacheMetrics
 from repro.kvcache.radix import RadixTree
+from repro.obs import trace as otrace
 
 __all__ = ["Admission", "KVCacheManager", "PoolExhausted"]
 
@@ -52,11 +53,20 @@ class KVCacheManager:
         self.metrics = CacheMetrics()
 
     # ------------------------------------------------------------ admission
-    def admit(self, prompt, total_tokens: int) -> Admission:
+    def admit(self, prompt, total_tokens: int, *, tid: int = 0) -> Admission:
         """Reserve blocks covering `total_tokens` positions for a request
         with this prompt, reusing the longest cached prefix. Evicts cold
         radix chains under pressure; raises PoolExhausted (reserving
-        nothing) if the pool still cannot cover the request."""
+        nothing) if the pool still cannot cover the request. Spanned as
+        `kv.admit` on trace track `tid`, with arg `evicted` (pages)."""
+        with otrace.span("kv.admit", tid=tid) as span:
+            evicted0 = self.metrics.blocks_evicted
+            try:
+                return self._admit(prompt, total_tokens)
+            finally:
+                span.set(evicted=self.metrics.blocks_evicted - evicted0)
+
+    def _admit(self, prompt, total_tokens: int) -> Admission:
         bs = self.pool.block_size
         n_total = max(1, -(-total_tokens // bs))        # ceil
         # cap reuse at len(prompt)-1: at least one prompt token must run
@@ -126,13 +136,16 @@ class KVCacheManager:
         self.pool.decref([src])
 
     # ------------------------------------------------------------ lifecycle
-    def commit(self, tokens, blocks: List[int]):
+    def commit(self, tokens, blocks: List[int], *, tid: int = 0):
         """Index the blocks fully covered by `tokens` in the radix tree so
         future prompts sharing the prefix reuse them. Safe to call with a
-        chain longer than the token run — only full chunks are stored."""
-        n_full = len(tokens) // self.pool.block_size
-        if n_full:
-            self.metrics.inserts += self.radix.insert(tokens, blocks[:n_full])
+        chain longer than the token run — only full chunks are stored.
+        Spanned as `kv.commit` on trace track `tid`."""
+        with otrace.span("kv.commit", tid=tid):
+            n_full = len(tokens) // self.pool.block_size
+            if n_full:
+                self.metrics.inserts += self.radix.insert(tokens,
+                                                          blocks[:n_full])
 
     def release(self, blocks: List[int]):
         """Request done: return its references. Blocks also indexed by the

@@ -1,9 +1,11 @@
 """Observability subsystem: tracing, metrics, workloads, SLOs, post-mortems.
 
-`trace` records named wall-clock spans along the request path (gateway
-submit -> dispatch -> engine step -> jit dispatch -> retire) into a ring
-buffer and exports Chrome trace events loadable in Perfetto; disabled by
-default and near-free when off. `registry` unifies the per-silo metric
+`trace` times named spans along the request path (gateway submit ->
+worker pump -> engine step -> jit dispatch -> device wait -> retire): as
+`jax.profiler` annotations while a profile collects, as `jax.monitoring`
+duration events for any listener, and, while armed, into a ring buffer
+exported as Chrome trace events loadable in Perfetto; a shared no-op when
+nothing observes it. `registry` unifies the per-silo metric
 counters (gateway, kvcache, speculation, scheduler) behind one
 `MetricsRegistry` whose `snapshot()` is the single serving-telemetry
 dict — see `Gateway.snapshot()` and `core.reporting.unified_dashboard`.

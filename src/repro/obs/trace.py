@@ -1,36 +1,47 @@
-"""Span tracer: ring-buffered, disabled by default, Perfetto-exportable.
+"""Span tracer: one span call, on the device trace's clock.
 
-Where does a token's latency actually go — queue wait, chunk planning, jit
-dispatch, pool scatter, retire? The per-silo counters answer "how much"
-but never "when"; this module records *spans* (named, nested wall-clock
-intervals) along the full request path and exports them as Chrome trace
-events (the JSON the Perfetto UI at https://ui.perfetto.dev loads
-directly), so a multi-request run becomes a zoomable timeline instead of
-a table of percentiles.
+Where does a token's latency actually go — queue wait, admission, jit
+dispatch, waiting on the device, retire? Each ``span(name, **args)`` at an
+instrumentation site does three things:
 
-Design constraints, in order:
+  * **Profiler.** While a ``jax.profiler`` capture is collecting (an
+    operator's XProf capture, or a benchmark's traced run) it enters a
+    ``jax.profiler.TraceAnnotation(name, **args)``, so the capture holds
+    the program's spans on the same host plane and clock as the device's
+    operations: an idle gap on the device lines up with what the host was
+    doing.
+  * **Monitoring.** On exit it reports its duration through
+    ``jax.monitoring.record_event_duration_secs("/repro/" + name, secs,
+    **args)``. Any registered duration listener sees every span; with none
+    registered that is one empty loop.
+  * **Ring.** Only while an operator has armed it (``enable()``, the serve
+    launcher's ``--trace OUT.json``), finished spans land in a ring buffer
+    (``capacity`` spans, oldest dropped first, drops counted) exported as
+    Chrome trace events, the JSON the Perfetto UI at
+    https://ui.perfetto.dev loads directly.
 
-  * **Zero cost when off.** Tracing is process-global and disabled by
-    default; ``span()`` then returns a shared no-op context manager — one
-    function call and one ``is None`` check per instrumentation site, no
-    allocation. Instrumented hot loops (one span per engine step, not per
-    token per slot) stay honest: the gateway benchmark machine-checks the
-    enabled-tracing overhead under 3% tokens/s.
-  * **Bounded when on.** Finished spans land in a ring buffer
-    (``capacity`` spans, oldest dropped first, drops counted) so a
-    long-lived frontend can leave tracing on without unbounded growth.
-  * **Device time attributed, not hidden.** An async dispatch returns
-    before the device finishes; the next host op then blocks and the
-    device time is mis-charged to *it*. ``fence(x)`` calls
-    ``jax.block_until_ready`` — only while tracing is enabled — inside
-    the dispatch span, so "jit.decode" means dispatch + device compute.
+``add_span`` reports a span whose ends are known only afterwards (a
+request's wait in the engine's queue; a worker's idle time, known to have
+ended once the next pump has stepped) to monitoring, with arg ``late_us``
+saying how long after its end, and to the ring; the profiler has no
+retroactive form, so it holds no such span.
+
+Arguments are ``str | int`` (``jax.monitoring``'s contract). Counts ride
+as arguments: there is no second counter system. A span times what the
+host does and never waits on the device itself: ``jit.*`` spans cover the
+dispatch only, and ``engine.wait`` covers the point where the engine
+already blocks on a result. With no profile collecting, no listener and no
+ring, ``span()`` returns a shared no-op (``python -m benchmarks.span_cost``
+measures what each state costs).
 
 Track layout in the export: pid 1 ("serving host") holds the host/engine
 spans, one tid per engine replica (the gateway itself shares tid 0 with
 replica 0, which it drives synchronously). pid 2 ("requests") holds one
 tid per request: a ``req<gid>`` span covering submit -> retire with
 ``queued`` / ``running`` phase spans nested inside — the Fig 6/7 queue
-story, but per request and zoomable.
+story, but per request and zoomable. pid 3 ("engine queues") holds one
+tid per engine request: its ``engine.pending`` wait, from the engine
+taking it to its admission into a slot.
 """
 from __future__ import annotations
 
@@ -41,8 +52,14 @@ from collections import deque
 from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
 
+from jax._src import monitoring as _monitoring
+from jax.monitoring import record_event_duration_secs
+from jax.profiler import TraceAnnotation
+
 HOST_PID = 1        # gateway/engine/jit spans, tid = replica id
 REQUEST_PID = 2     # request-lifetime spans, tid = request gid
+ENGINE_QUEUE_PID = 3    # engine.pending spans, tid = engine request id
+EVENT_PREFIX = "/repro/"    # jax.monitoring event = EVENT_PREFIX + span name
 
 
 class _Span:
@@ -59,12 +76,29 @@ class _Span:
         self.args = args
 
 
-class _ActiveSpan:
-    """Context manager recording one span into its tracer on exit."""
-    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0")
+def _report(tracer: Optional["Tracer"], name: str, t0: float, t1: float,
+            cat: str, pid: int, tid: int, args: Dict[str, Any],
+            late_us: Optional[int] = None):
+    """A finished span to every listener of jax.monitoring, and to the
+    ring of `tracer` when one is armed. A span reported after its end
+    tells listeners by how much (`late_us`), so they can place it."""
+    if late_us is None:
+        record_event_duration_secs(EVENT_PREFIX + name, t1 - t0, **args)
+    else:
+        record_event_duration_secs(EVENT_PREFIX + name, t1 - t0, **args,
+                                   late_us=late_us)
+    if tracer is not None:
+        tracer._record(_Span(name, cat, t0, t1 - t0, pid, tid,
+                             dict(args) if args else None))
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int,
-                 args: Optional[Dict[str, Any]]):
+
+class _ActiveSpan:
+    """Context manager for one span: a profiler annotation while a profile
+    is collecting, reported on exit (see the module docstring)."""
+    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_ann", "_t0")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 tid: int, args: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._cat = cat
@@ -72,29 +106,53 @@ class _ActiveSpan:
         self._args = args
 
     def __enter__(self) -> "_ActiveSpan":
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self._name, **self._args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **args):
+        """Args known only at the span's end (the pages an admission
+        evicted); reported with the rest on exit."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __exit__(self, *exc) -> bool:
-        self._tracer._record(_Span(
-            self._name, self._cat, self._t0,
-            time.perf_counter() - self._t0, HOST_PID, self._tid,
-            self._args))
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _report(self._tracer, self._name, self._t0, t1, self._cat, HOST_PID,
+                self._tid, self._args)
         return False
 
 
 class _NullSpan:
-    """Shared no-op span: the disabled-tracing fast path."""
+    """Shared no-op span: nothing would see this one."""
     __slots__ = ()
 
     def __enter__(self) -> "_NullSpan":
         return self
+
+    def set(self, **args):
+        pass
 
     def __exit__(self, *exc) -> bool:
         return False
 
 
 _NULL = _NullSpan()
+
+
+def _unobserved() -> bool:
+    """No ring armed, no duration listener registered (jax.monitoring has
+    no public getter for its listener list) and no profile collecting."""
+    return (_TRACER is None
+            and not _monitoring._event_duration_secs_listeners
+            and not TraceAnnotation.is_enabled())
 
 
 class Tracer:
@@ -124,14 +182,17 @@ class Tracer:
     # -------------------------------------------------------- recording
     def span(self, name: str, *, cat: str = "serve", tid: int = 0,
              **args) -> _ActiveSpan:
-        return _ActiveSpan(self, name, cat, tid, args or None)
+        """A span recorded into this tracer's ring (and reported like any
+        other)."""
+        return _ActiveSpan(self, name, cat, tid, args)
 
     def add_span(self, name: str, t0: float, t1: float, *,
                  cat: str = "serve", pid: int = HOST_PID, tid: int = 0,
                  args: Optional[Dict[str, Any]] = None):
-        """Record a span retroactively from perf_counter endpoints — the
-        request-lifetime spans are emitted this way at retire time, from
-        the timestamps `GatewayMetrics` already keeps."""
+        """Record a span into the ring alone, retroactively from
+        perf_counter endpoints — the request-lifetime spans are emitted
+        this way at retire time, from the timestamps `GatewayMetrics`
+        already keeps."""
         self._record(_Span(name, cat, t0, max(t1 - t0, 0.0), pid, tid,
                            args))
 
@@ -171,7 +232,8 @@ class Tracer:
             ring = list(self._ring)
         evs = []
         for pid, pname in ((HOST_PID, "serving host"),
-                           (REQUEST_PID, "requests")):
+                           (REQUEST_PID, "requests"),
+                           (ENGINE_QUEUE_PID, "engine queues")):
             evs.append({"ph": "M", "name": "process_name", "pid": pid,
                         "tid": 0, "ts": 0,
                         "args": {"name": pname}})
@@ -245,18 +307,24 @@ def enabled() -> bool:
 
 
 def span(name: str, *, cat: str = "serve", tid: int = 0, **args):
-    """Instrumentation-site entry point: a real span while tracing is
-    enabled, the shared no-op otherwise."""
-    t = _TRACER
-    if t is None:
+    """Instrumentation-site entry point: a context manager timing the
+    enclosed code as span `name` with str/int `args`; the shared no-op
+    when nothing would see it."""
+    if _unobserved():
         return _NULL
-    return t.span(name, cat=cat, tid=tid, **args)
+    return _ActiveSpan(_TRACER, name, cat, tid, args)
 
 
-def add_span(name: str, t0: float, t1: float, **kw):
-    t = _TRACER
-    if t is not None:
-        t.add_span(name, t0, t1, **kw)
+def add_span(name: str, t0: float, t1: float, *, cat: str = "serve",
+             pid: int = HOST_PID, tid: int = 0, **args):
+    """Report a span from perf_counter endpoints known only afterwards:
+    to monitoring, with arg `late_us` (microseconds from its end to now),
+    and to the ring while one is armed."""
+    if _TRACER is None and not _monitoring._event_duration_secs_listeners:
+        return
+    t1 = max(t1, t0)
+    _report(_TRACER, name, t0, t1, cat, pid, tid, args,
+            late_us=int((time.perf_counter() - t1) * 1e6))
 
 
 def set_track_name(pid: int, tid: int, name: str):
@@ -268,28 +336,3 @@ def set_track_name(pid: int, tid: int, name: str):
     t = _TRACER
     if t is not None:
         t.set_track_name(pid, tid, name)
-
-
-def fence(x):
-    """Block on a jax computation — only while tracing — so device time
-    lands in the enclosing dispatch span instead of whichever host op
-    touches the result next. Returns `x` either way."""
-    if _TRACER is not None:
-        import jax
-        jax.block_until_ready(x)
-    return x
-
-
-def traced(name: Optional[str] = None, *, cat: str = "serve"):
-    """Decorator form of `span` for whole-function spans."""
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        def wrapper(*a, **kw):
-            with span(label, cat=cat):
-                return fn(*a, **kw)
-        wrapper.__name__ = fn.__name__
-        wrapper.__qualname__ = fn.__qualname__
-        wrapper.__doc__ = fn.__doc__
-        return wrapper
-    return deco

@@ -100,6 +100,9 @@ class Request:
     output: List[int] = field(default_factory=list)
     done: bool = False
     error: Optional[BaseException] = field(default=None, repr=False)
+    # perf_counter when an engine last took it (enqueue): the start of its
+    # `engine.pending` span, which its admission into a slot ends
+    enqueued_t: float = field(default=0.0, repr=False, compare=False)
 
     def __post_init__(self):
         self._sampler = Sampler(self.sampling)
@@ -314,6 +317,7 @@ class ServeEngine:
             raise ValueError(
                 f"request needs {len(req.prompt) + req.max_new_tokens} "
                 f"token positions, table holds {self.cache_len}")
+        req.enqueued_t = time.perf_counter()
         self._pending.append(req)
         return req
 
@@ -454,7 +458,8 @@ class ServeEngine:
                     req = self._pending[0]
                     try:
                         adm = self.manager.admit(
-                            req.prompt, len(req.prompt) + req.max_new_tokens)
+                            req.prompt, len(req.prompt) + req.max_new_tokens,
+                            tid=self.trace_tid)
                     except PoolExhausted as err:
                         if self.active_count() == 0:
                             # nothing in flight will ever free blocks: the
@@ -471,6 +476,10 @@ class ServeEngine:
                             continue
                         break       # retry after a running request retires
                 req = self._pending.pop(0)
+                otrace.add_span("engine.pending", req.enqueued_t,
+                                time.perf_counter(),
+                                pid=otrace.ENGINE_QUEUE_PID,
+                                tid=req.request_id)
                 self.active[slot] = req
                 if self.scheduler is not None:
                     self._begin_chunked_prefill(slot, req, adm)
@@ -499,9 +508,12 @@ class ServeEngine:
         (block chain + reused-prefix length) from the manager."""
         t0 = time.perf_counter()
         tok0 = self.prefill_tokens_computed
+        reused = adm.n_reused if adm is not None else 0
+        tokens = len(req.prompt) - reused
         with otrace.span("engine.step", tid=self.trace_tid, step="prefill",
                          slot=slot, prompt_len=len(req.prompt),
-                         reused=(adm.n_reused if adm is not None else 0)):
+                         reused=reused, tokens=tokens,
+                         padded=self._prefill_padded(tokens)):
             self._prefill_slot_impl(slot, req, adm)
         # share basis: prompt tokens actually computed (min 1 — a full
         # prefix hit still occupied the dispatch); blocks may already be 0
@@ -510,6 +522,14 @@ class ServeEngine:
         self._observe_step("prefill", t0,
                            [(req.request_id, computed,
                              self._blocks_held(slot))])
+
+    def _prefill_padded(self, tokens: int) -> int:
+        """Positions the prefill of `tokens` uncached prompt tokens
+        computes: bulk prefill pads them to bucket_len where it buckets
+        (every paged engine does), the token-at-a-time path runs them."""
+        if self.prefill_mode == "bulk" and self._bucket_prompts and tokens:
+            return bucket_len(tokens, self.cache_len)
+        return tokens
 
     def _prefill_slot_impl(self, slot: int, req: Request, adm=None):
         greedy = req.sampling.is_greedy
@@ -531,10 +551,18 @@ class ServeEngine:
                 pos = jnp.zeros((self.slots,), jnp.int32).at[slot].set(t)
                 out, cache = decode(self.params, toks, pos, self.cache)
                 self.cache = _merge_slot(self.cache, cache, slot)
-            first = int(out[slot]) if greedy else \
-                self._sample_safe(req, np.asarray(out[slot]))
+            first = self._first_token(req, out[slot])
             self.prefill_tokens_computed += len(req.prompt)
         self._finish_prefill(slot, req, first)
+
+    def _first_token(self, req: Request, out):
+        """Read a prefill's last-position result (the token, or the logits
+        row to sample): where the engine blocks on the prefill program."""
+        with otrace.span("engine.wait", tid=self.trace_tid,
+                         program="prefill"):
+            out = np.asarray(out)
+        return int(out) if req.sampling.is_greedy else \
+            self._sample_safe(req, out)
 
     def _finish_prefill(self, slot: int, req: Request, first):
         """Post-prefill bookkeeping shared by the phased and chunked paths:
@@ -605,8 +633,7 @@ class ServeEngine:
                 self.params, toks, jnp.asarray(start, jnp.int32),
                 jnp.asarray(len(suffix), jnp.int32), self.cache,
                 jnp.asarray(self.table[slot]))
-            first = int(out) if greedy else \
-                self._sample_safe(req, np.asarray(out))
+            first = self._first_token(req, out)
         else:
             decode = self._decode_tok if greedy else self._decode_lg
             # peers' rows masked to the null block: their lockstep garbage
@@ -621,11 +648,11 @@ class ServeEngine:
                 pos = jnp.zeros((self.slots,), jnp.int32).at[slot].set(t)
                 out, self.cache = decode(self.params, toks, pos,
                                          self.cache, tbl)
-            first = int(out[slot]) if greedy else \
-                self._sample_safe(req, np.asarray(out[slot]))
+            first = self._first_token(req, out[slot])
         # index the prompt's full pages: the next request sharing this
         # prefix reuses them instead of re-running prefill
-        self.manager.commit(req.prompt, self._slot_blocks[slot])
+        self.manager.commit(req.prompt, self._slot_blocks[slot],
+                            tid=self.trace_tid)
         return first
 
     def _bulk_prefill_slot(self, slot: int, req: Request) -> int:
@@ -661,8 +688,7 @@ class ServeEngine:
         merged["tail"] = jax.tree.map(lambda f, o: write(f, o, 0),
                                       self.cache["tail"], slot_cache["tail"])
         self.cache = merged
-        return int(out[0]) if greedy else \
-            self._sample_safe(req, np.asarray(out[0]))
+        return self._first_token(req, out[0])
 
     def _release_slot_blocks(self, slot: int, req: Optional[Request],
                              commit: bool = True):
@@ -675,7 +701,7 @@ class ServeEngine:
             return
         if commit and req is not None:
             written = (req.prompt + req.output)[:int(self.pos[slot]) + 1]
-            self.manager.commit(written, blocks)
+            self.manager.commit(written, blocks, tid=self.trace_tid)
         self.manager.release(blocks)
         self._slot_blocks[slot] = []
         self.table[slot, :] = 0
@@ -735,11 +761,13 @@ class ServeEngine:
         # reconcile loop can retire slots and release them
         shares = [(self.active[s].request_id, 1, self._blocks_held(s))
                   for s in live]
+        # keys each live slot attends: its written positions and this one
+        keys = int(self.pos[live].sum()) + 2 * len(live)
         with otrace.span("engine.step", tid=self.trace_tid, step="decode",
-                         live=len(live)):
+                         live=len(live), keys=keys):
             decode = self._decode_tok if greedy_batch else self._decode_lg
             with otrace.span("jit.decode", tid=self.trace_tid,
-                             kind="single", greedy=greedy_batch):
+                             kind="single", greedy=int(greedy_batch)):
                 if self.kv_layout == "paged":
                     # no merge needed: every live slot scatters exactly
                     # into its own frontier page; empty slots' zero tables
@@ -751,8 +779,9 @@ class ServeEngine:
                     out, new_cache = decode(self.params, jnp.asarray(toks),
                                             jnp.asarray(pos), self.cache)
                     self.cache = _merge_slots(self.cache, new_cache, live)
-                otrace.fence((out, self.cache))
-            out = np.asarray(out)
+            with otrace.span("engine.wait", tid=self.trace_tid,
+                             program="decode"):
+                out = np.asarray(out)
             for s in live:
                 req = self.active[s]
                 self.pos[s] += 1
@@ -836,9 +865,9 @@ class ServeEngine:
                 jnp.asarray(plan.start, jnp.int32),
                 jnp.asarray(len(plan.tokens), jnp.int32),
                 jnp.asarray(self.table[plan.slot, :nbp]))
-            otrace.fence((out_d, out_c, self.cache))
         sched.mixed_dispatches += 1
-        out_d = np.asarray(out_d)
+        with otrace.span("engine.wait", tid=self.trace_tid, program="mixed"):
+            out_d = np.asarray(out_d)
         for s in decode_live:
             req = self.active[s]
             self.pos[s] += 1
@@ -858,7 +887,8 @@ class ServeEngine:
         sched.advance(plan)
         self.prefill_tokens_computed += len(plan.tokens)
         cur = plan.start + len(plan.tokens)
-        self.manager.commit(creq.prompt[:cur], self._slot_blocks[plan.slot])
+        self.manager.commit(creq.prompt[:cur], self._slot_blocks[plan.slot],
+                            tid=self.trace_tid)
         if plan.completes:
             first = self._sample_safe(creq, np.asarray(out_c)) \
                 if need_logits else int(out_c)
@@ -894,10 +924,10 @@ class ServeEngine:
                 self.params, jnp.asarray(toks), jnp.asarray(pos), self.cache,
                 jnp.asarray(self.table), jnp.asarray(eos), jnp.asarray(alive),
                 jnp.asarray(steps))
-            otrace.fence((emitted, self.cache))
-        emitted = np.asarray(emitted)
-        live_out = np.asarray(live_out)
-        steps_out = np.asarray(steps_out)
+        with otrace.span("engine.wait", tid=self.trace_tid, program="fused"):
+            emitted = np.asarray(emitted)
+            live_out = np.asarray(live_out)
+            steps_out = np.asarray(steps_out)
         shares = []
         for s in live:
             req = self.active[s]
@@ -950,8 +980,8 @@ class ServeEngine:
             out, self.cache = self._decode_spec(
                 self.params, jnp.asarray(toks), jnp.asarray(pos), self.cache,
                 jnp.asarray(self.table), jnp.asarray(inp))
-            otrace.fence((out, self.cache))
-        out = np.asarray(out)           # one packed transfer (see builder)
+        with otrace.span("engine.wait", tid=self.trace_tid, program="verify"):
+            out = np.asarray(out)       # one packed transfer (build_decode_spec)
         emitted, adv, n_acc, live_out, steps_out = \
             out[:K + 1], out[K + 1], out[K + 2], out[K + 3], out[K + 4]
         self.spec_dispatches += 1

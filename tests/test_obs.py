@@ -8,11 +8,13 @@ must not change a single token on any decode path), the strict
 request-lifecycle state machine, and the None-not-NaN empty-series
 percentile fix.
 """
+import glob
 import json
 import math
 
 import jax
 import pytest
+from jax._src import monitoring
 
 from repro.configs.base import ModelConfig
 from repro.core import reporting
@@ -54,6 +56,38 @@ def _no_leaked_tracer():
     otrace.disable()
     yield
     otrace.disable()
+
+
+@pytest.fixture
+def no_listeners(monkeypatch):
+    """No jax.monitoring duration listener, whatever another test of this
+    process registered."""
+    monkeypatch.setattr(monitoring, "_event_duration_secs_listeners", [])
+
+
+@pytest.fixture
+def heard(no_listeners):
+    """What one duration listener hears of the program's spans."""
+    got = []
+
+    def on(event, secs, **args):
+        if event.startswith(otrace.EVENT_PREFIX):
+            got.append((event, secs, args))
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return got
+
+
+def _profiled(log_dir, fn):
+    """fn() while a jax.profiler capture collects into log_dir (host
+    spans only: the Python tracer off keeps the capture quick)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        return fn()
+    finally:
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------- registry
@@ -138,7 +172,7 @@ class TestInstruments:
 # ------------------------------------------------------------------ tracer
 
 class TestTracer:
-    def test_disabled_is_noop_singleton(self):
+    def test_disabled_is_noop_singleton(self, no_listeners):
         assert not otrace.enabled()
         s = otrace.span("x")
         assert s is otrace.span("y")        # shared null object
@@ -173,16 +207,40 @@ class TestTracer:
         assert st == {"enabled": True, "capacity": 8, "spans_recorded": 1,
                       "spans_buffered": 1, "spans_dropped": 0}
 
-    def test_traced_decorator(self):
-        tr = otrace.enable()
+    def test_monitoring_listener_hears_span_name_duration_and_args(
+            self, heard):
+        """With no ring armed, a listener still hears every span: its
+        name under /repro/, its duration, its int/str args, and args set
+        before it ends."""
+        with otrace.span("work", tid=3, items=2, kind="a") as sp:
+            sp.set(evicted=4)
+        otrace.add_span("queue", 1.0, 1.25, n=7)
+        (work, secs, wargs), (queue, qsecs, qargs) = heard
+        assert (work, wargs) == ("/repro/work",
+                                 {"items": 2, "kind": "a", "evicted": 4})
+        assert secs >= 0
+        # reported after the fact, it says how long after its end
+        late = qargs.pop("late_us")
+        assert (queue, qsecs, qargs) == ("/repro/queue", 0.25, {"n": 7})
+        assert late >= 0
+        assert otrace.active() is None
 
-        @otrace.traced("labelled")
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2
-        assert [e["name"] for e in tr.events() if e["ph"] == "X"] \
-            == ["labelled"]
+    def test_unobserved_span_records_nothing(self, no_listeners):
+        """Entered with no listener, profile or ring, a span does no work:
+        a listener or ring that arrives before it ends hears nothing of
+        it, and add_span then reports nowhere."""
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        heard = []
+        span = otrace.span("early", items=1)
+        with span:
+            jax.monitoring.register_event_duration_secs_listener(
+                lambda e, s, **a: heard.append(e))
+            tr = otrace.enable()
+        assert heard == [] and tr.recorded == 0
+        otrace.disable()
+        monitoring._event_duration_secs_listeners.clear()
+        otrace.add_span("late", 0.0, 1.0, items=1)
+        assert heard == []
 
     def test_export_is_valid_chrome_trace(self, tmp_path):
         tr = otrace.enable()
@@ -194,14 +252,6 @@ class TestTracer:
         doc = json.loads(path.read_text())
         assert set(doc) == {"traceEvents", "displayTimeUnit"}
         _assert_trace_schema(doc["traceEvents"])
-
-    def test_fence_is_identity(self):
-        x = {"a": 1}
-        assert otrace.fence(x) is x         # disabled: no jax import even
-        otrace.enable()
-        import jax.numpy as jnp
-        y = jnp.ones(3)
-        assert otrace.fence(y) is y
 
     def test_capacity_validation(self):
         with pytest.raises(ValueError):
@@ -251,10 +301,11 @@ def _assert_trace_schema(events):
 # --------------------------------------------------- parity + engine wiring
 
 @pytest.mark.parametrize("path", sorted(PATHS))
-def test_tracing_parity_and_step_spans(model, path):
+def test_tracing_parity_and_step_spans(model, path, tmp_path):
     """Greedy row of the decode-path parity matrix, tracing as the
-    variable: span recording must not change one token, and every path
-    must leave engine.step spans tagged with its step type."""
+    variable: span recording, and a profile collecting, must not change
+    one token, and every path must leave engine.step spans tagged with its
+    step type."""
     params, cfg = model
     kw = dict(PATHS[path])
     if kw.get("kv_layout") == "paged":
@@ -274,6 +325,8 @@ def test_tracing_parity_and_step_spans(model, path):
     traced, eng = drive()
     otrace.disable()
     assert traced == baseline, f"tracing changed tokens on {path}"
+    profiled, _ = _profiled(tmp_path, drive)
+    assert profiled == baseline, f"profiling changed tokens on {path}"
     steps = [e for e in tr.events()
              if e["ph"] == "X" and e["name"] == "engine.step"]
     assert steps, f"no engine.step spans on {path}"
@@ -285,6 +338,40 @@ def test_tracing_parity_and_step_spans(model, path):
     assert eng.step_summary() is not None
     assert expect in eng.step_summary()
     _assert_trace_schema(tr.events())
+
+
+def test_profile_holds_engine_spans_on_the_host_plane(model, tmp_path):
+    """A jax.profiler capture of an engine run holds the engine's spans,
+    with their args, on the host plane the device ops share a clock
+    with."""
+    params, cfg = model
+
+    def drive():
+        eng = ServeEngine(params, cfg, batch_slots=2, cache_len=32,
+                          kv_layout="paged", block_size=4,
+                          prefill_mode="bulk")
+        for p in PROMPTS:
+            eng.submit(p, max_new_tokens=3)
+        eng.run()
+
+    _profiled(tmp_path, drive)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    host = [e for p in data.planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events]
+    args = {}
+    for e in host:
+        args.setdefault(e.name, []).append(dict(e.stats))
+    assert {"engine.step", "engine.wait", "jit.decode", "kv.admit",
+            "kv.commit"} <= set(args)
+    assert {a["program"] for a in args["engine.wait"]} \
+        == {"decode", "prefill"}
+    prefills = [a for a in args["engine.step"] if a["step"] == "prefill"]
+    assert len(prefills) == len(PROMPTS)
+    assert all(a["padded"] >= a["tokens"] for a in prefills)
+    decodes = [a for a in args["engine.step"] if a["step"] == "decode"]
+    assert decodes and all(a["keys"] > a["live"] > 0 for a in decodes)
+    assert all("evicted" in a for a in args["kv.admit"])
 
 
 def test_gateway_trace_covers_every_finished_request(model, tmp_path):
