@@ -2,7 +2,8 @@
 
 ``kernel="pallas"`` dispatches to the Pallas kernel (compiled on a TPU;
 elsewhere it runs in the Pallas interpreter, ``repro.kernels.
-default_interpret``, so the same BlockSpecs/grid are exercised everywhere);
+default_interpret``, so the same grid, DMAs and semaphores are exercised
+everywhere);
 ``kernel="reference"`` runs the dense-gather oracle (ref.py), which is the
 pre-kernel production path and the CPU fallback of record.
 

@@ -1,10 +1,10 @@
 """The Pallas kernels compile for a TPU v5e at the published widths of
-the configs that use them (qwen3-1.7b for attention). The chip is
-described, not attached (jax.experimental.topologies): the TPU compiler
-refuses here what it would refuse on the chip — a block shape off the
-(8, 128) tiling, an unsupported primitive — which the interpret-mode tests
-cannot see. Nothing runs, so this says nothing about
-results or times.
+the configs that use them (qwen3-1.7b for attention; mistral-nemo-12b too
+for paged decode). The chip is described, not attached
+(jax.experimental.topologies): the TPU compiler refuses here what it would
+refuse on the chip — a block shape off the (8, 128) tiling, an unsupported
+primitive — which the interpret-mode tests cannot see. Nothing runs, so
+this says nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each test worker imports
@@ -47,16 +47,22 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _paged_decode(spec):
-    # serving shapes: 8 slots, 16-token pages, 1024-token tables, pool of
-    # 2 * 8 * 64 pages plus the null page
+def _paged_decode(spec, B=32, nh=NH, nb=96):
+    # served shapes: 16-token pages, a pool of twice the slots' tables plus
+    # the null page; qwen3-1.7b by default (32 slots, 1536-token tables)
     from repro.kernels.paged_attention.kernel import paged_attention_kernel
-    B, bs, nb = 8, 16, 64
+    bs = 16
     P = 2 * B * nb + 1
-    args = (spec((B, NH, HD), DT), spec((P, NKV, bs, HD), DT),
+    args = (spec((B, nh, HD), DT), spec((P, NKV, bs, HD), DT),
             spec((P, NKV, bs, HD), DT), spec((B, nb), jnp.int32),
             spec((B,), jnp.int32))
     return functools.partial(paged_attention_kernel, interpret=False), args
+
+
+def _paged_decode_mistral(spec):
+    # mistral-nemo-12b: GQA 32/8 (rep 4), 16 slots, 2064-token tables
+    # (129 pages, prime: the last block of pages is partial)
+    return _paged_decode(spec, B=16, nh=32, nb=129)
 
 
 def _flash_attention(spec):
@@ -85,7 +91,9 @@ def _ssd_scan(spec):
     return functools.partial(ssd_scan, chunk_size=256, interpret=False), args
 
 
-KERNELS = {"paged_decode": _paged_decode, "flash_attention": _flash_attention,
+KERNELS = {"paged_decode": _paged_decode,
+           "paged_decode_mistral": _paged_decode_mistral,
+           "flash_attention": _flash_attention,
            "rglru_scan": _rglru_scan, "ssd_scan": _ssd_scan}
 
 

@@ -1,15 +1,26 @@
 """Paged-attention decode kernel vs the dense-gather oracle (ref.py),
-interpret mode (kernel body executes in Python on CPU; grid/BlockSpecs are
+in the TPU interpreter (kernel body executes on CPU with the TPU's VMEM,
+DMAs and semaphores simulated, never-written VMEM read as NaN; the grid is
 identical to the TPU lowering). Covers the GQA group shapes, partially
-filled frontier pages, null-page (empty/retired) slots, and the
-window=None-only guard, plus the wiring through layers/transformer."""
+filled frontier pages, null-page (empty/retired) slots, blocks of pages
+past the frontier, and the window=None-only guard, plus the wiring
+through layers/transformer."""
 import jax
+import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
+
+
+@pytest.fixture(autouse=True)
+def tpu_interpreter():
+    """Every kernel call here runs in the TPU interpreter; the serving path
+    off the chip keeps the faster generic one (``interpret=True``)."""
+    with pltpu.force_tpu_interpret_mode():
+        yield
 
 
 def _chain(pool_rows, bs, nb, fill_tokens):
@@ -45,6 +56,15 @@ CASES = [
     (3, 4, 8, 1, 4, 64, (9, 1, 27)),        # MQA, frontier pages mid-fill
     (4, 3, 16, 2, 2, 32, (17, 0, 48, 0)),   # null-page (empty) slots mixed in
     (1, 6, 8, 2, 3, 16, (41,)),             # long chain, ragged tail page
+    # the served geometries (bs 16, hd 128, nkv 8): several pages per grid
+    # step, so nb 20 and 33 end in a partial block (16 + 4 and 16 + 16 + 1
+    # pages in bf16, 8 + 8 + 4 and 4 x 8 + 1 in f32)
+    (2, 20, 16, 8, 2, 128, (300, 17)),      # qwen3-1.7b: rep 2
+    (2, 33, 16, 8, 4, 128, (520, 0)),       # mistral-nemo-12b: rep 4
+    (3, 20, 16, 8, 2, 128, (257, 256, 1)),  # frontier on a block boundary
+                                            # (pos 256, 255) and pos 0
+    (3, 40, 16, 8, 2, 128, (40, 0, 600)),   # live slots beside blocks
+                                            # wholly past the frontier
 ]
 
 
@@ -77,18 +97,65 @@ def test_empty_slot_rows_are_zero():
 def test_beyond_frontier_pages_do_not_leak():
     """Pages past the causal frontier (allocated-but-unwritten budget pages
     full of stale garbage) must not affect the output: poisoning them
-    changes nothing."""
-    q, kpool, vpool, table, pos = _case(2, 6, 8, 2, 2, 32, (12, 12))
-    out = paged_attention(q, kpool, vpool, table, pos, kernel="pallas",
-                          interpret=True)
-    frontier = 12 // 8                       # pages 2.. are beyond
-    poison_rows = np.asarray(table)[:, frontier + 1:].ravel()
-    poison_rows = poison_rows[poison_rows != 0]
-    kp = kpool.at[poison_rows].set(1e4)
-    vp = vpool.at[poison_rows].set(-1e4)
-    out2 = paged_attention(q, kp, vp, table, pos, kernel="pallas",
-                           interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+    changes nothing. Covered in one block of pages and in several, where
+    whole blocks lie past the frontier and the frontier's block holds dead
+    pages; the frontier page's own tail is poisoned too."""
+    for B, nb, bs, nkv, rep, hd, fills in [(2, 6, 8, 2, 2, 32, (12, 12)),
+                                           (2, 20, 16, 8, 2, 128, (40, 200))]:
+        q, kpool, vpool, _, pos = _case(B, nb, bs, nkv, rep, hd, fills)
+        # the whole table allocated, as with budget pages reserved ahead
+        table = jnp.arange(1, B * nb + 1, dtype=jnp.int32).reshape(B, nb)
+        out = paged_attention(q, kpool, vpool, table, pos, kernel="pallas",
+                              interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(paged_attention_ref(
+                q, kpool, vpool, table, pos)), atol=2e-5, rtol=2e-5)
+        kp, vp = kpool, vpool
+        for b in range(B):
+            frontier = int(pos[b]) // bs
+            dead = np.asarray(table)[b, frontier + 1:]
+            kp = kp.at[dead].set(jnp.nan).at[table[b, frontier], :,
+                                             int(pos[b]) % bs + 1:].set(1e4)
+            vp = vp.at[dead].set(jnp.inf).at[table[b, frontier], :,
+                                             int(pos[b]) % bs + 1:].set(-1e4)
+        out2 = paged_attention(q, kp, vp, table, pos, kernel="pallas",
+                               interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+def test_pages_per_block_rule():
+    """Pages per grid step come from shapes alone: the served geometries
+    get at least 4, their double buffer of K and V stays within the
+    kernel's stated VMEM budget, a short table caps the block, and the
+    grid a call builds is (B, ceil(nb / ppb)) whatever the table holds."""
+    from repro.kernels.paged_attention.kernel import (KV_BUFFER_BYTES,
+                                                      pages_per_block)
+    for nb in (96, 129):                    # qwen3-1.7b, mistral-nemo-12b
+        ppb = pages_per_block(nb, 16, 8, 128, 2)
+        assert ppb >= 4
+        assert 2 * 2 * ppb * 8 * 16 * 128 * 2 <= KV_BUFFER_BYTES
+    assert pages_per_block(3, 16, 8, 128, 2) == 3
+    assert pages_per_block(96, 16, 8, 128, 4) == \
+        pages_per_block(96, 16, 8, 128, 2) // 2
+
+    def grid(B, nb, fills):
+        args = _case(B, nb, 16, 8, 2, 128, fills)
+        jaxpr = jax.make_jaxpr(lambda *a: paged_attention(
+            *a, kernel="pallas", interpret=True))(*args)
+        found = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(eqn.params["grid_mapping"].grid)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        return found
+
+    ppb = pages_per_block(33, 16, 8, 128, 4)
+    assert grid(2, 33, (520, 0)) == grid(2, 33, (1, 17)) == \
+        [(2, -(-33 // ppb))]
 
 
 def test_window_guard():
