@@ -2,7 +2,8 @@
 
 After the window has closed and the program's state is freed, a sample of
 the requests the window finished, drawn from the seed and always holding
-the longest one, is run through the plain float32 reference (`reference`)
+the longest one, is run through the plain float32 reference (`reference`,
+with the steps of the configuration's architecture module)
 over each prompt with its served tokens. A served token's gap is how far
 its reference logit lies below the reference's best at that position
 (greedy decoding serves the best token, so a sound program reads only
@@ -49,8 +50,8 @@ def sample(done: Sequence[dict], seed: int,
     return [done[i] for i in ([first] + rest)[:max_requests]]
 
 
-def readings(c: dict, seed: int, picked: Sequence[dict], traffic: dict,
-             control: bool = False) -> Dict[str, float]:
+def readings(arch, c: dict, seed: int, picked: Sequence[dict],
+             traffic: dict, control: bool = False) -> Dict[str, float]:
     """tokens_compared and served_logit_gap_{max,mean}, served_not_first;
     with control=True the same of the int8 reference's first choices
     (control_*), read against the float32 reference at the same
@@ -69,10 +70,11 @@ def readings(c: dict, seed: int, picked: Sequence[dict], traffic: dict,
     out = {"tokens_compared": len(served)}
     sets = [served]
     if control:
-        q = Reference(c, seed, quant="int8").evaluate(seqs, rows, lengths,
-                                                      max_rows)
+        q = Reference(arch, c, seed, quant="int8").evaluate(
+            seqs, rows, lengths, max_rows)
         sets.append(q["argmax"].tolist())
-    ref = Reference(c, seed).evaluate(seqs, rows, lengths, max_rows, sets)
+    ref = Reference(arch, c, seed).evaluate(seqs, rows, lengths, max_rows,
+                                            sets)
     gap = ref["best"][None, :] - ref["got"]
     for name, g in zip(("served", "control"), gap):
         out[f"{name}_logit_gap_max"] = float(g.max())

@@ -35,7 +35,6 @@ from typing import Callable, Dict, List, Optional
 
 from . import check, endtoend, spec, system, trace as tr
 from .traffic import Generator
-from .work import Shape
 
 TRACE_SECONDS = 8.0         # the profiled end of a --trace 1 run's window
 GRACE_S = 60.0              # wait past the close for the window's answers
@@ -338,8 +337,9 @@ class Run:
     peaks: object = None
 
     @property
-    def shape(self) -> Shape:
-        return Shape.from_config(self.cell.config)
+    def shape(self):
+        """The architecture's work counts for this configuration."""
+        return self.cell.arch.work(self.cell.config)
 
     def traced(self, calls):
         """The calls recorded while the profiler ran."""
@@ -372,11 +372,11 @@ def run(cell: spec.Cell, bench_dir: str, checkout: str, seed: int,
     system.import_program(checkout)
     c, traffic = cell.config, cell.traffic
     serving = c["serving"]
-    mcfg = system.model_config(c)
+    mcfg = cell.arch.program_config(c)
     phases["program import"] = time.perf_counter() - t
     with CompileCounter() as clock:
         t = time.perf_counter()
-        params = system.make_params(c, mcfg, seed)
+        params = system.make_params(cell.arch, c, mcfg, seed)
         phases["weights"] = time.perf_counter() - t
         t = time.perf_counter()
         gw = system.build_gateway(params, mcfg, serving)
@@ -471,7 +471,8 @@ def run(cell: spec.Cell, bench_dir: str, checkout: str, seed: int,
     limits = cell.limits
     picked = check.sample(done, seed, limits["check"]["max_requests"])
     t = time.perf_counter()
-    values = check.readings(c, seed, picked, traffic, control=control)
+    values = check.readings(cell.arch, c, seed, picked, traffic,
+                            control=control)
     log(f"readings: {values}")
     log(f"reference over {len(picked)} requests took "
         f"{time.perf_counter() - t!r} s")

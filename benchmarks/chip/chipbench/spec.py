@@ -6,17 +6,26 @@ Layout under the benchmark's directory:
     traffic/<traffic>.json    parameters of one traffic mix
     cells/<workload>.json     the correctness limits of one cell
     metrics/<metric>.py       the reader of one per-layer metric
+    archs/<model_type>.py     all that depends on the architecture, for the
+                              configurations whose `model_type` it is
+                              (`ARCH_PARTS`; see archs/qwen3.py)
 
-A later change adds a cell by adding such files and an entry in
-BENCHMARK.json; no file here lists them.
+A later change adds a cell, or an architecture, by adding such files and an
+entry in BENCHMARK.json; no file here lists them.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
 from typing import Dict, List
+
+
+ARCH_PARTS = ("program_config", "program_params", "layer_tensors",
+              "global_tensors", "embed", "layer", "head", "work")
 
 
 class SpecError(Exception):
@@ -28,6 +37,7 @@ class Cell:
     name: str
     config: dict            # the configuration file's contents
     config_entry: dict      # its entry in BENCHMARK.json
+    arch: object            # archs/<its model_type>.py
     traffic_name: str
     traffic: dict
     limits: dict            # cells/<name>.json
@@ -66,10 +76,14 @@ def load_cell(checkout: str, bench_dir: str, workload: str,
     if w["config"] not in configs:
         raise SpecError(f"workload {workload}: no config {w['config']!r}")
     entry = configs[w["config"]]
+    config = _read_json(os.path.join(checkout, entry["file"]))
+    if "model_type" not in config:
+        raise SpecError(f"{entry['file']} states no model_type")
     return Cell(
         name=workload,
-        config=_read_json(os.path.join(checkout, entry["file"])),
+        config=config,
         config_entry=entry,
+        arch=arch(bench_dir, config["model_type"]),
         traffic_name=w["traffic"],
         traffic=_read_json(os.path.join(bench_dir, "traffic",
                                         w["traffic"] + ".json")),
@@ -81,6 +95,23 @@ def load_cell(checkout: str, bench_dir: str, workload: str,
         per_layer=[m for m in bench.get("per_layer", [])
                    if _reports(m, workload)],
     )
+
+
+def arch(bench_dir: str, model_type: str):
+    """The module archs/<model_type>.py, imported as `archs.<model_type>`
+    with the benchmark's directory on sys.path (where run.py puts it), so
+    that a module reaches its siblings the same way; there is no
+    fallback."""
+    path = os.path.join(bench_dir, "archs", model_type + ".py")
+    if not os.path.isfile(path):
+        raise SpecError(f"no module {path} for model_type {model_type!r}")
+    if bench_dir not in sys.path:
+        sys.path.insert(0, bench_dir)
+    mod = importlib.import_module("archs." + model_type)
+    missing = [p for p in ARCH_PARTS if not callable(getattr(mod, p, None))]
+    if missing:
+        raise SpecError(f"{path} defines no {missing}")
+    return mod
 
 
 def metric_reader(bench_dir: str, name: str):

@@ -9,14 +9,16 @@ Keys are of JAX's "rbg" kind, whose bits come from XLA's RngBitGenerator:
 far cheaper on a TPU than threefry, and the same for a key and a shape in
 any program, though not under vmap, so layers are drawn in a loop.
 
-Names follow the published checkpoints (q_proj, ..., down_proj). Norm
-tensors are drawn as the offset u of the weight 1 + u, so that random norm
-weights are exercised as well as the identity.
+Which tensors there are is the architecture's (`archs/<model_type>.py`:
+`layer_tensors`, `global_tensors`), as (name, shape, kind); the tensor
+index is its place in that list. Norm tensors are drawn as the offset u of
+the weight 1 + u, so that random norm weights are exercised as well as the
+identity.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,8 @@ import jax.numpy as jnp
 GLOBAL, LAYER = 0, 1
 NORM_SPREAD = 0.2       # norm weights are 1 + U(-0.2, 0.2)
 EMBED_STD = 0.02        # embedding rows ~ N(0, 0.02^2)
+
+Tensors = List[Tuple[str, tuple, str]]      # (name, shape, kind) in order
 
 
 def base_key(seed: int):
@@ -39,33 +43,9 @@ def dtype_of(c: dict):
     return jnp.dtype(c["torch_dtype"])
 
 
-def layer_tensors(c: dict) -> List[Tuple[str, tuple, str]]:
-    d, hd, ff = c["hidden_size"], c["head_dim"], c["intermediate_size"]
-    nh, nkv = c["num_attention_heads"], c["num_key_value_heads"]
-    out = [("input_norm", (d,), "norm"),
-           ("q_proj", (d, nh * hd), "dense"),
-           ("k_proj", (d, nkv * hd), "dense"),
-           ("v_proj", (d, nkv * hd), "dense"),
-           ("o_proj", (nh * hd, d), "dense")]
-    if c["qk_norm"]:
-        out += [("q_norm", (hd,), "norm"), ("k_norm", (hd,), "norm")]
-    return out + [("post_norm", (d,), "norm"),
-                  ("gate_proj", (d, ff), "dense"),
-                  ("up_proj", (d, ff), "dense"),
-                  ("down_proj", (ff, d), "dense")]
-
-
-def global_tensors(c: dict) -> List[Tuple[str, tuple, str]]:
-    d, v = c["hidden_size"], c["vocab_size"]
-    out = [("embed", (v, d), "embed"), ("final_norm", (d,), "norm")]
-    if not c["tie_word_embeddings"]:
-        out.append(("lm_head", (d, v), "dense"))
-    return out
-
-
 def _draw(key, shape, kind, dtype):
-    if kind == "dense":
-        s = 1.0 / math.sqrt(shape[0])
+    if kind == "dense":      # fan-in: the second axis from the end
+        s = 1.0 / math.sqrt(shape[-2])
         return jax.random.uniform(key, shape, dtype, -s, s)
     if kind == "norm":
         return jax.random.uniform(key, shape, dtype, -NORM_SPREAD,
@@ -75,27 +55,30 @@ def _draw(key, shape, kind, dtype):
     raise ValueError(kind)
 
 
-def layer(key, c: dict, i) -> Dict[str, jax.Array]:
+def layer(key, c: dict, tensors: Tensors, i) -> Dict[str, jax.Array]:
     """Layer i's tensors (i may be a traced loop index)."""
     k = jax.random.fold_in(jax.random.fold_in(key, LAYER), i)
     return {name: _draw(jax.random.fold_in(k, j), shape, kind, dtype_of(c))
-            for j, (name, shape, kind) in enumerate(layer_tensors(c))}
+            for j, (name, shape, kind) in enumerate(tensors)}
 
 
-def stacked_layers(key, c: dict) -> Dict[str, jax.Array]:
-    """Every layer's tensors stacked on a leading axis, drawn one layer at
-    a time into buffers updated in place (no second copy of the model)."""
-    n = c["num_hidden_layers"]
-    one = jax.eval_shape(lambda: layer(key, c, 0))
+def stacked_layers(key, c: dict, tensors: Tensors,
+                   layers: Sequence[int]) -> Dict[str, jax.Array]:
+    """The tensors of the given layers, all of one kind, stacked on a
+    leading axis in that order, drawn one layer at a time into buffers
+    updated in place (no second copy of the model)."""
+    idx = jnp.asarray(list(layers), jnp.int32)
+    n = idx.shape[0]
+    one = jax.eval_shape(lambda: layer(key, c, tensors, 0))
     init = {k: jnp.zeros((n,) + v.shape, v.dtype) for k, v in one.items()}
 
-    def body(i, acc):
-        w = layer(key, c, i)
-        return {k: acc[k].at[i].set(w[k]) for k in acc}
+    def body(j, acc):
+        w = layer(key, c, tensors, idx[j])
+        return {k: acc[k].at[j].set(w[k]) for k in acc}
     return jax.lax.fori_loop(0, n, body, init)
 
 
-def globals_(key, c: dict) -> Dict[str, jax.Array]:
+def globals_(key, c: dict, tensors: Tensors) -> Dict[str, jax.Array]:
     k = jax.random.fold_in(key, GLOBAL)
     return {name: _draw(jax.random.fold_in(k, j), shape, kind, dtype_of(c))
-            for j, (name, shape, kind) in enumerate(global_tensors(c))}
+            for j, (name, shape, kind) in enumerate(tensors)}

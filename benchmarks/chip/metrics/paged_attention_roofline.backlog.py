@@ -13,6 +13,6 @@ def read(run):
     kernel = run.trace.kernels.get(run.programs["decode"])
     if run.peaks is None or not calls or not kernel or kernel[1] <= 0:
         return None
-    least = [work.least_time(*work.paged_attention_call(run.shape, live),
+    least = [work.least_time(*run.shape.paged_attention_call(live),
                              run.peaks)[0] for live in calls]
     return 100.0 * (sum(least) / len(least)) * kernel[0] / kernel[1]

@@ -11,17 +11,21 @@ import numpy as np
 import pytest
 
 import tinycell
-from chipbench import reference, system, weights
+from chipbench import BENCH_DIR, reference, spec, system, weights
 
 SEED = 2 ** 33 + 5          # above 32 bits on purpose
 
 
+def _arch(c):
+    return spec.arch(BENCH_DIR, c["model_type"])
+
+
 def _served_logits(c, seed, prompt, n_decode):
     from repro.models import transformer as T
-    mcfg = system.model_config(c)
+    mcfg = _arch(c).program_config(c)
     s = c["serving"]
     bs, nb = s["block_size"], s["cache_len"] // s["block_size"]
-    params = system.make_params(c, mcfg, seed)
+    params = system.make_params(_arch(c), c, mcfg, seed)
     cache = T.init_paged_cache(mcfg, s["pool_blocks"], bs)
     table = np.zeros((s["batch_slots"], nb), np.int32)
     table[0] = np.arange(1, nb + 1)          # slot 0 owns pages 1..nb
@@ -57,7 +61,7 @@ def test_reference_matches_served_prefill_and_paged_decode(c):
     seq, served = _served_logits(c, SEED, prompt, n_decode=5)
     n, v = len(seq), c["vocab_size"]
     every = [list(range(v))] * n          # every token at every row
-    ref = reference.Reference(c, SEED).evaluate(
+    ref = reference.Reference(_arch(c), c, SEED).evaluate(
         [seq], [list(range(n))], [64], max_rows=n,
         token_sets=np.asarray(every).T.tolist())
     full = ref["got"].T                    # (rows, vocab)
@@ -73,25 +77,26 @@ def test_int8_control_departs_from_the_reference():
     c = tinycell.QWEN
     seq = np.random.default_rng(1).integers(0, c["vocab_size"], 40).tolist()
     args = ([seq], [list(range(40))], [64], 40)
-    ref = reference.Reference(c, SEED).evaluate(*args)
-    ctl = reference.Reference(c, SEED, quant="int8").evaluate(*args)
+    ref = reference.Reference(_arch(c), c, SEED).evaluate(*args)
+    ctl = reference.Reference(_arch(c), c, SEED, quant="int8").evaluate(*args)
     assert not np.allclose(ref["best"], ctl["best"], atol=1e-4)
 
 
 def test_program_and_reference_draw_the_same_weights():
     c = tinycell.MISTRAL
+    arch = _arch(c)
     key = weights.base_key(SEED)
-    tree = jax.jit(lambda k: system._program_tree(k, c))(key)
+    tree = jax.jit(lambda k: arch.program_params(k, c))(key)
     blk = tree["blocks"][0]
     names = {"q_proj": blk["attn"]["wq"], "o_proj": blk["attn"]["wo"],
              "down_proj": blk["ffn"]["w_down"],
              "post_norm": blk["norm2"]["scale"]}
     for i in range(c["num_hidden_layers"]):
-        w = weights.layer(key, c, i)
+        w = weights.layer(key, c, arch.layer_tensors(c, i), i)
         for name, stacked in names.items():
             assert np.array_equal(np.asarray(stacked[i]),
                                   np.asarray(w[name])), (name, i)
-    g = weights.globals_(key, c)
+    g = weights.globals_(key, c, arch.global_tensors(c))
     assert np.array_equal(np.asarray(tree["lm_head"]),
                           np.asarray(g["lm_head"]))
 

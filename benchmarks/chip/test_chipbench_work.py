@@ -5,20 +5,19 @@ import os
 
 import pytest
 
-from chipbench import peaks, work
-
-HERE = os.path.dirname(os.path.abspath(__file__))
+from chipbench import BENCH_DIR, peaks, spec, work
 
 
 def _shape(name):
-    with open(os.path.join(HERE, "configs", name + ".json")) as f:
-        return work.Shape.from_config(json.load(f))
+    with open(os.path.join(BENCH_DIR, "configs", name + ".json")) as f:
+        c = json.load(f)
+    return spec.arch(BENCH_DIR, c["model_type"]).work(c)
 
 
 def test_paged_attention_counts_live_tokens_by_hand():
     s = _shape("qwen3-1.7b")     # 16 query heads, 8 KV heads, hd 128, bf16
     live = [1, 17, 300, 1536]    # ragged: one key, a page and one, ...
-    flops, bytes_ = work.paged_attention_call(s, live)
+    flops, bytes_ = s.paged_attention_call(live)
     keys = 1 + 17 + 300 + 1536
     # QK^T and PV: 2 FLOPs per multiply-add, 16 heads x 128 each
     assert flops == 2 * 2 * 16 * 128 * keys
@@ -26,7 +25,7 @@ def test_paged_attention_counts_live_tokens_by_hand():
     # slot's q read and output written (16 x 128 x 2 bytes each)
     assert bytes_ == 2 * 8 * 128 * 2 * keys + 2 * 16 * 128 * 2 * 4
     # empty slots cost nothing: the count is of work, not of pages walked
-    assert work.paged_attention_call(s, []) == (0.0, 0.0)
+    assert s.paged_attention_call([]) == (0.0, 0.0)
 
 
 def test_least_time_takes_the_binding_peak():
@@ -51,9 +50,9 @@ def test_model_flops_by_hand():
     assert s.layer_matmul_params == per_layer
     # one decoded token attending 10 keys
     want = 10 * (2 * per_layer + 4 * 32 * hd * 10) + 2 * d * v
-    assert work.decode_token_flops(s, 10) == want
+    assert s.decode_token_flops(10) == want
     # a 3-token prefill after 5 cached tokens attends 6 + 7 + 8 keys
     want = 10 * (2 * per_layer * 3 + 4 * 32 * hd * 21) + 2 * d * v
-    assert work.prefill_flops(s, 5, 3) == want
+    assert s.prefill_flops(5, 3) == want
     assert work.model_flops(s, [[10], [10, 10]], [(5, 3)]) == \
-        3 * work.decode_token_flops(s, 10) + work.prefill_flops(s, 5, 3)
+        3 * s.decode_token_flops(10) + s.prefill_flops(5, 3)

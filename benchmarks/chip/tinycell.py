@@ -9,19 +9,21 @@ import copy
 import json
 import os
 import shutil
+from typing import Dict, Optional
 
 from chipbench import BENCH_DIR, CHECKOUT, spec
 
 SERVING = {"kv_layout": "paged", "prefill_mode": "bulk",
            "decode_kernel": "pallas", "batch_slots": 4, "cache_len": 96,
            "block_size": 16, "pool_blocks": 49}
-QWEN = {"name": "tiny-qwen", "source": "test", "hidden_size": 128,
-        "intermediate_size": 256, "num_attention_heads": 4,
+QWEN = {"name": "tiny-qwen", "source": "test", "model_type": "qwen3",
+        "hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 32, "num_hidden_layers": 2,
         "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
         "tie_word_embeddings": True, "torch_dtype": "float32",
         "hidden_act": "silu", "qk_norm": True, "serving": SERVING}
-MISTRAL = dict(QWEN, name="tiny-mistral", hidden_size=160, rms_norm_eps=1e-5,
+MISTRAL = dict(QWEN, name="tiny-mistral", model_type="mistral",
+               hidden_size=160, rms_norm_eps=1e-5,
                tie_word_embeddings=False, qk_norm=False)
 CLOSED = {"loop": "closed", "in_flight_per_slot": 1.5,
           "prompt_tokens": {"median": 24, "sigma": 0.7, "min": 8, "max": 64},
@@ -34,16 +36,22 @@ LIMITS = {"check": {"max_requests": 5},
                      "replica_failures": {"max": 0}}}
 
 
-def make(tmp: str) -> str:
+def make(tmp: str, archs: Optional[Dict[str, str]] = None,
+         qwen_model_type: str = "qwen3") -> str:
     """A checkout under tmp holding this benchmark and two tiny cells:
-    tiny-qwen.closed and tiny-mistral.open. Returns its path."""
+    tiny-qwen.closed and tiny-mistral.open. `archs` maps a model_type to
+    the source of an archs/<model_type>.py added to the copy;
+    tiny-qwen's configuration names `qwen_model_type`. Returns its path."""
     root = os.path.join(tmp, "checkout")
     bench = os.path.join(root, os.path.relpath(BENCH_DIR, CHECKOUT))
     shutil.copytree(BENCH_DIR, bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
+    for name, source in (archs or {}).items():
+        with open(os.path.join(bench, "archs", name + ".py"), "w") as f:
+            f.write(source)
     os.symlink(os.path.join(CHECKOUT, "src"), os.path.join(root, "src"))
     doc = copy.deepcopy(spec.load_benchmark(CHECKOUT))
-    for c in (QWEN, MISTRAL):
+    for c in (dict(QWEN, model_type=qwen_model_type), MISTRAL):
         rel = os.path.join(os.path.relpath(bench, root), "configs",
                            c["name"] + ".json")
         _write(os.path.join(root, rel), c)
